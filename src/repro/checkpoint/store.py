@@ -116,12 +116,17 @@ class CheckpointManager:
         self.keep = keep
         self.async_save = async_save
         self._thread: Optional[threading.Thread] = None
+        self._error: Optional[Exception] = None
         os.makedirs(directory, exist_ok=True)
 
     def wait(self) -> None:
+        """Block until the pending async save lands; re-raise its error."""
         if self._thread is not None:
             self._thread.join()
             self._thread = None
+        if self._error is not None:
+            err, self._error = self._error, None
+            raise err
 
     def save(self, tree: Any, step: int) -> None:
         # Snapshot to host synchronously (cheap, avoids racing mutation),
@@ -133,8 +138,14 @@ class CheckpointManager:
             save_tree(host_tree, self.directory, step)
             self._gc()
 
+        def _write_async():
+            try:
+                _write()
+            except Exception as e:  # raised again by the next wait()
+                self._error = e
+
         if self.async_save:
-            self._thread = threading.Thread(target=_write, daemon=True)
+            self._thread = threading.Thread(target=_write_async, daemon=True)
             self._thread.start()
         else:
             _write()

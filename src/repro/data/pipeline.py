@@ -53,21 +53,31 @@ class SyntheticTokens:
         return out
 
     def iter(self, start_step: int = 0, prefetch: int = 2) -> Iterator[dict]:
-        """Background-thread prefetching iterator."""
+        """Background-thread prefetching iterator.
+
+        An exception in the worker is handed through the queue and raised
+        in the consumer, which would otherwise wait forever.
+        """
         q: queue.Queue = queue.Queue(maxsize=prefetch)
         stop = threading.Event()
 
         def worker():
             s = start_step
-            while not stop.is_set():
-                q.put(self.sample(s))
-                s += 1
+            try:
+                while not stop.is_set():
+                    q.put(self.sample(s))
+                    s += 1
+            except Exception as e:
+                q.put(e)
 
         t = threading.Thread(target=worker, daemon=True)
         t.start()
         try:
             while True:
-                yield q.get()
+                item = q.get()
+                if isinstance(item, Exception):
+                    raise item
+                yield item
         finally:
             stop.set()
 

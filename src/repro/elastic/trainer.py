@@ -92,10 +92,12 @@ class ElasticTrainer:
             have = (pool.n_nodes if pool is not None
                     else f"{len(jax.devices())} devices")
             raise ValueError(
-                f"scenario {scenario.name!r} peaks at {scenario.max_nodes()} "
-                f"nodes ({width}) but the host/pool only has {have}; set "
-                f"XLA_FLAGS=--xla_force_host_platform_device_count={need} "
-                "before importing jax, or pass a larger pool"
+                f"scenario {scenario.name!r} needs {need} chips: it peaks at "
+                f"{scenario.max_nodes()} nodes ({width}), but the host/pool "
+                f"only has {have}. Run it on a host with {need} chips "
+                f"(`chip_smoke.py --chips 4` runs steady-cycle on four), or "
+                f"on the CPU set XLA_FLAGS=--xla_force_host_platform_device_"
+                f"count={need} before importing jax; or pass a larger pool"
             )
         runtime = ElasticRuntime(
             pool=pool,

@@ -1,11 +1,14 @@
 """Chunked mLSTM Pallas kernel (TPU target, xLSTM arXiv:2405.04517).
 
 Grid (B, H, n_chunks), chunk innermost; the matrix memory S (D, D), the
-normalizer n (D,) and the stabilizer m (scalar) persist in VMEM scratch
+normalizer n (1, D) and the stabilizer m (scalar) persist in VMEM scratch
 across the sequential chunk dimension.  All gating math is fp32.
 
 Layouts (pre-transposed by ops.py):
-  q/k/v (B, H, nc, Q, D)   ig/fg (B, H, nc, Q)   ->  h (B, H, nc, Q, D)
+  q/k/v (B, H, nc, Q, D)   ig/fg (B, H, 1, S)   ->  h (B, H, nc, Q, D)
+
+On the chip, Q must be a multiple of 128 (or all of S): the gate blocks
+are (1, Q) rows.
 """
 from __future__ import annotations
 
@@ -16,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .tiles import chunk_masks, col_to_row, cumsum_col, row_to_col
 
 NEG = -1e30
 
@@ -31,62 +36,58 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, ig_ref, fg_ref, h_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG)
 
     Q, D = chunk, head_dim
+    causal, eye = chunk_masks(Q)
     q = q_ref[0, 0, 0].astype(jnp.float32) / math.sqrt(D)   # (Q, D)
     k = k_ref[0, 0, 0].astype(jnp.float32)
     v = v_ref[0, 0, 0].astype(jnp.float32)
-    ig = ig_ref[0, 0, 0].astype(jnp.float32)                # (Q,)
-    logf = jax.nn.log_sigmoid(fg_ref[0, 0, 0].astype(jnp.float32))
+    ig = ig_ref[0, 0].astype(jnp.float32)                   # (1, Q)
+    logf = jax.nn.log_sigmoid(fg_ref[0, 0].astype(jnp.float32))
 
-    b = jnp.cumsum(logf)                                    # (Q,)
-    total = b[-1]
-    m_p = m_ref[0, 0]
+    b_col = cumsum_col(logf, causal)                        # (Q, 1)
+    b = col_to_row(b_col, eye)                              # (1, Q)
+    ig_col = row_to_col(ig, eye)                            # (Q, 1)
+    total = b[:, Q - 1:]                                    # (1, 1)
+    m_p = m_ref[...]                                        # (1, 1)
 
     # intra log-weights: l_ij = b_i - b_j + ig_j  (j <= i)
-    diff = b[:, None] - b[None, :] + ig[None, :]
-    mask = (
-        jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-        <= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
-    )
-    diff = jnp.where(mask, diff, NEG)
-    m_intra = jnp.max(diff, axis=1)                         # (Q,)
+    diff = jnp.where(causal, b_col - b + ig, NEG)           # (Q, Q)
+    m_intra = jnp.max(diff, axis=1, keepdims=True)          # (Q, 1)
 
     # per-position stabilizer
-    m_i = jnp.maximum(m_p + b, m_intra)                     # (Q,)
-    inter_scale = jnp.exp(m_p + b - m_i)
-    inter_scale = jnp.where(m_p <= NEG, 0.0, inter_scale)
+    m_i = jnp.maximum(m_p + b_col, m_intra)                 # (Q, 1)
+    inter_scale = jnp.where(m_p <= NEG, 0.0, jnp.exp(m_p + b_col - m_i))
 
     num = jax.lax.dot_general(
         q, s_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ) * inter_scale[:, None]
-    den = (q @ n_ref[...].reshape(D, 1))[:, 0] * inter_scale
+    ) * inter_scale
+    den = jnp.sum(q * n_ref[...], axis=1, keepdims=True) * inter_scale
 
     qk = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )                                                       # (Q, Q)
-    wts = jnp.exp(diff - m_i[:, None])
-    wts = jnp.where(mask, wts, 0.0)
+    wts = jnp.where(causal, jnp.exp(diff - m_i), 0.0)
     num += jax.lax.dot_general(
         qk * wts, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    den += jnp.sum(qk * wts, axis=1)
+    den += jnp.sum(qk * wts, axis=1, keepdims=True)
 
-    h = num / jnp.maximum(jnp.abs(den), jnp.exp(-m_i))[:, None]
+    h = num / jnp.maximum(jnp.abs(den), jnp.exp(-m_i))
     h_ref[0, 0, 0] = h.astype(h_ref.dtype)
 
     # state update (stabilized)
-    w = total - b + ig                                      # (Q,)
-    m_chunk = jnp.max(w)
+    w = total - b_col + ig_col                              # (Q, 1)
+    m_chunk = jnp.max(w, axis=0, keepdims=True)             # (1, 1)
     m_new = jnp.maximum(m_p + total, m_chunk)
     scale_old = jnp.where(m_p <= NEG, 0.0, jnp.exp(m_p + total - m_new))
-    cw = jnp.exp(w - m_new)                                 # (Q,)
+    kw = k * jnp.exp(w - m_new)                             # (Q, D)
     s_ref[...] = s_ref[...] * scale_old + jax.lax.dot_general(
-        k * cw[:, None], v, (((0,), (0,)), ((), ())),
+        kw, v, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    n_ref[...] = n_ref[...] * scale_old + jnp.sum(k * cw[:, None], axis=0)
-    m_ref[0, 0] = m_new
+    n_ref[...] = n_ref[...] * scale_old + jnp.sum(kw, axis=0, keepdims=True)
+    m_ref[...] = m_new
 
 
 def mlstm_scan_pallas(
@@ -108,8 +109,10 @@ def mlstm_scan_pallas(
         return jnp.moveaxis(a, 2, 1).reshape(B, H, nc, Q, *a.shape[3:])
 
     qt, kt, vt = tr(q), tr(k), tr(v)
-    igt = jnp.moveaxis(i_gate, 2, 1).reshape(B, H, nc, Q)
-    fgt = jnp.moveaxis(f_gate, 2, 1).reshape(B, H, nc, Q)
+    # Gates as lane-major rows: a (1, Q) block of (B, H, 1, S) meets the
+    # (8, 128) tiling rule where a (Q,) block of (B, H, nc, Q) does not.
+    igt = jnp.moveaxis(i_gate, 2, 1).reshape(B, H, 1, S)
+    fgt = jnp.moveaxis(f_gate, 2, 1).reshape(B, H, 1, S)
 
     kernel = functools.partial(_mlstm_kernel, chunk=Q, head_dim=D)
     h = pl.pallas_call(
@@ -119,14 +122,14 @@ def mlstm_scan_pallas(
             pl.BlockSpec((1, 1, 1, Q, D), lambda b, h_, c: (b, h_, c, 0, 0)),
             pl.BlockSpec((1, 1, 1, Q, D), lambda b, h_, c: (b, h_, c, 0, 0)),
             pl.BlockSpec((1, 1, 1, Q, D), lambda b, h_, c: (b, h_, c, 0, 0)),
-            pl.BlockSpec((1, 1, 1, Q), lambda b, h_, c: (b, h_, c, 0)),
-            pl.BlockSpec((1, 1, 1, Q), lambda b, h_, c: (b, h_, c, 0)),
+            pl.BlockSpec((1, 1, 1, Q), lambda b, h_, c: (b, h_, 0, c)),
+            pl.BlockSpec((1, 1, 1, Q), lambda b, h_, c: (b, h_, 0, c)),
         ],
         out_specs=pl.BlockSpec((1, 1, 1, Q, D), lambda b, h_, c: (b, h_, c, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, nc, Q, D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((D, D), jnp.float32),
-            pltpu.VMEM((D,), jnp.float32),
+            pltpu.VMEM((1, D), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
         interpret=interpret,

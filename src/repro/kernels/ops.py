@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On TPU these call the pallas kernels directly; on CPU (this container)
-``interpret=True`` executes the kernel bodies in Python for correctness
-validation against :mod:`repro.kernels.ref`.
+They compile for the TPU.  ``interpret=True`` executes the kernel bodies
+in Python instead, for correctness checks against
+:mod:`repro.kernels.ref` on a host without a chip; nothing picks it for
+the caller.
 """
 from __future__ import annotations
 
@@ -15,32 +16,25 @@ from .mlstm import mlstm_scan_pallas
 from .ssd import ssd_scan_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(
     jax.jit, static_argnames=("causal", "window", "softcap", "block_q", "block_k", "interpret")
 )
 def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
-                    block_q=128, block_k=128, interpret=None):
+                    block_q=128, block_k=128, interpret=False):
     """Flash attention.  q (B,H,Sq,D); k/v (B,KV,Sk,D) -> (B,H,Sq,D)."""
-    interp = (not _on_tpu()) if interpret is None else interpret
     return flash_attention_pallas(
         q, k, v, causal=causal, window=window, softcap=softcap,
-        block_q=block_q, block_k=block_k, interpret=interp,
+        block_q=block_q, block_k=block_k, interpret=interpret,
     )
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, A, Bmat, Cmat, *, chunk=128, interpret=None):
+def ssd_scan(x, dt, A, Bmat, Cmat, *, chunk=128, interpret=False):
     """Mamba2 SSD.  x (B,S,H,P), dt (B,S,H), A (H,), B/C (B,S,N)."""
-    interp = (not _on_tpu()) if interpret is None else interpret
-    return ssd_scan_pallas(x, dt, A, Bmat, Cmat, chunk=chunk, interpret=interp)
+    return ssd_scan_pallas(x, dt, A, Bmat, Cmat, chunk=chunk, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def mlstm_scan(q, k, v, i_gate, f_gate, *, chunk=128, interpret=None):
+def mlstm_scan(q, k, v, i_gate, f_gate, *, chunk=128, interpret=False):
     """Chunked mLSTM.  q/k/v (B,S,H,D), gates (B,S,H)."""
-    interp = (not _on_tpu()) if interpret is None else interpret
-    return mlstm_scan_pallas(q, k, v, i_gate, f_gate, chunk=chunk, interpret=interp)
+    return mlstm_scan_pallas(q, k, v, i_gate, f_gate, chunk=chunk, interpret=interpret)

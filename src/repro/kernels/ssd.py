@@ -7,9 +7,12 @@ tile, one (Q, N) B/C tile pair and the (Q, P) output tile, which is what
 makes the chunked formulation memory-optimal on TPU.
 
 Layouts (pre-transposed by ops.py):
-  x  (B, H, nc, Q, P)   dt (B, H, nc, Q)
-  Bm (B, nc, Q, N)      Cm (B, nc, Q, N)     A (H,)
+  x  (B, H, nc, Q, P)   dt, dt*A (B, H, 1, S)
+  Bm (B, nc, Q, N)      Cm (B, nc, Q, N)
   -> y (B, H, nc, Q, P)
+
+On the chip, Q must be a multiple of 128 (or all of S): the dt blocks
+are (1, Q) rows.
 """
 from __future__ import annotations
 
@@ -20,8 +23,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .tiles import chunk_masks, col_to_row, cumsum_col, row_to_col
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *, chunk: int):
+
+def _ssd_kernel(x_ref, dt_ref, da_ref, b_ref, c_ref, y_ref, state_ref, *,
+                chunk: int):
     c_idx = pl.program_id(2)
 
     @pl.when(c_idx == 0)
@@ -29,26 +35,22 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *, chunk: 
         state_ref[...] = jnp.zeros_like(state_ref)
 
     Q = chunk
+    causal, eye = chunk_masks(Q)
     x = x_ref[0, 0, 0].astype(jnp.float32)        # (Q, P)
-    dt = dt_ref[0, 0, 0].astype(jnp.float32)      # (Q,)
-    A = a_ref[0].astype(jnp.float32)              # ()
+    dt = dt_ref[0, 0].astype(jnp.float32)         # (1, Q)
+    dA = da_ref[0, 0]                             # (1, Q) = dt * A_h, negative
     Bm = b_ref[0, 0].astype(jnp.float32)          # (Q, N)
     Cm = c_ref[0, 0].astype(jnp.float32)          # (Q, N)
 
-    dA = dt * A                                   # (Q,) negative
-    cum = jnp.cumsum(dA)                          # (Q,)
-    total = cum[-1]
+    cum_col = cumsum_col(dA, causal)              # (Q, 1)
+    cum = col_to_row(cum_col, eye)                # (1, Q)
+    total = cum[:, Q - 1:]                        # (1, 1)
 
     # intra-chunk: w_ij = (C_i . B_j) exp(cum_i - cum_j) dt_j  (j <= i)
     cb = jax.lax.dot_general(
         Cm, Bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )                                             # (Q, Q)
-    diff = cum[:, None] - cum[None, :]
-    mask = (
-        jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-        <= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
-    )
-    w = jnp.where(mask, cb * jnp.exp(diff) * dt[None, :], 0.0)
+    w = jnp.where(causal, cb * jnp.exp(cum_col - cum) * dt, 0.0)
     y = jax.lax.dot_general(
         w, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -57,12 +59,12 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *, chunk: 
     y += jax.lax.dot_general(
         Cm, state_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ) * jnp.exp(cum)[:, None]
+    ) * jnp.exp(cum_col)
 
     # state update: S = exp(total) S + sum_j exp(total - cum_j) dt_j B_j x_j^T
-    rem = jnp.exp(total - cum) * dt               # (Q,)
+    rem = jnp.exp(total - cum_col) * row_to_col(dt, eye)   # (Q, 1)
     state_ref[...] = state_ref[...] * jnp.exp(total) + jax.lax.dot_general(
-        Bm * rem[:, None], x, (((0,), (0,)), ((), ())),
+        Bm * rem, x, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
 
@@ -86,7 +88,12 @@ def ssd_scan_pallas(
     Q = chunk
 
     xt = jnp.moveaxis(x, 2, 1).reshape(B, H, nc, Q, P)
-    dtt = jnp.moveaxis(dt, 2, 1).reshape(B, H, nc, Q)
+    # Per-position vectors as lane-major rows: a (1, Q) block of
+    # (B, H, 1, S) meets the (8, 128) tiling rule where a (Q,) block of
+    # (B, H, nc, Q) does not.  A is folded in here, so no (1,)-block of
+    # the (H,) vector reaches the kernel.
+    dtt = jnp.moveaxis(dt, 2, 1).reshape(B, H, 1, S)
+    dAt = dtt.astype(jnp.float32) * A.astype(jnp.float32)[None, :, None, None]
     Bq = Bmat.reshape(B, nc, Q, N)
     Cq = Cmat.reshape(B, nc, Q, N)
 
@@ -96,8 +103,8 @@ def ssd_scan_pallas(
         grid=(B, H, nc),
         in_specs=[
             pl.BlockSpec((1, 1, 1, Q, P), lambda b, h, c: (b, h, c, 0, 0)),
-            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
+            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, 0, c)),
+            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, 0, c)),
             pl.BlockSpec((1, 1, Q, N), lambda b, h, c: (b, c, 0, 0)),
             pl.BlockSpec((1, 1, Q, N), lambda b, h, c: (b, c, 0, 0)),
         ],
@@ -105,5 +112,5 @@ def ssd_scan_pallas(
         out_shape=jax.ShapeDtypeStruct((B, H, nc, Q, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
         interpret=interpret,
-    )(xt, dtt, A, Bq, Cq)
+    )(xt, dtt, dAt, Bq, Cq)
     return jnp.moveaxis(y.reshape(B, H, S, P), 1, 2)

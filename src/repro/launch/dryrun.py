@@ -1,6 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: jax locks the device count on first init.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ MUST precede any jax import: jax locks the device count and the
+# platform on first init.  The dry-run simulates a 512-chip mesh on host
+# devices, so it never takes an accelerator from another process.
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
@@ -183,8 +186,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str) -> dict:
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax < 0.5 wraps it per-program
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
 
     # While-aware analysis: cost_analysis() counts scan bodies once on this

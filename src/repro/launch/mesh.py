@@ -6,17 +6,7 @@ touch jax device state (the dry-run sets XLA_FLAGS before first init).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 names explicit/auto axis types; older jax has neither.
-    from jax.sharding import AxisType
-
-    def _axis_types(n: int):
-        return {"axis_types": (AxisType.Auto,) * n}
-
-except ImportError:  # pragma: no cover - depends on installed jax
-
-    def _axis_types(n: int):
-        return {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -24,7 +14,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2 pods x 256 = 512 chips (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_parallel: int = 1, axis_names=("data", "model")):
@@ -34,5 +24,5 @@ def make_host_mesh(model_parallel: int = 1, axis_names=("data", "model")):
     return jax.make_mesh(
         (n // model_parallel, model_parallel),
         axis_names,
-        **_axis_types(2),
+        axis_types=(AxisType.Auto,) * 2,
     )

@@ -130,6 +130,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     if args.static:
         if not args.arch:
             ap.error("--static requires --arch")
+        from repro.launch import use_compile_cache
+
+        use_compile_cache()
         return _run_static(args)
 
     from repro.malleability.policies import SERVE_SCENARIO_NAMES

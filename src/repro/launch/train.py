@@ -1,8 +1,9 @@
 """Training driver: ``python -m repro.launch.train --arch <id> [...]``.
 
-Runs real steps on the host's devices (reduced config by default — the
-full configs only fit the production mesh, which is exercised via the
-dry-run).  Integrates the elastic runtime: pass ``--scenario <name>`` to
+Runs real steps on the host's devices: the reduced config by default,
+the published widths with ``--full-config`` (xlstm_125m fits one chip;
+the larger configs only fit the production mesh, which the dry-run
+exercises).  Integrates the elastic runtime: pass ``--scenario <name>`` to
 run the malleable loop against a registered declarative workload trace
 (grow/shrink/fail/straggler events planned by the ReconfigEngine).
 """
@@ -10,19 +11,22 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Optional, Sequence
 
 import jax
 
 from repro.configs import arch_config, smoke_config
 from repro.data import SyntheticTokens, make_batch_on_mesh
+from repro.launch import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import Model
 from repro.parallel.sharding import ShardingContext
-from repro.train.steps import build_train_step
+from repro.train.steps import TrainState, build_init_fn, build_train_step
 from repro.checkpoint import CheckpointManager
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> tuple[TrainState, list[float]]:
+    """Run the driver; returns the final train state and every step's loss."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=50)
@@ -37,20 +41,18 @@ def main() -> None:
     ap.add_argument("--scenario", default=None,
                     help="run the elastic loop against a registered scenario "
                          "(see repro.malleability.registered_scenarios)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = arch_config(args.arch) if args.full_config else smoke_config(args.arch)
     model = Model(cfg)
 
     if args.scenario:
-        run_scenario(model, args)
-        return
+        return run_scenario(model, args)
     mesh = make_host_mesh(args.model_parallel)
     ctx = ShardingContext(mesh=mesh, mode="train")
 
     step_fn, shardings, _ = build_train_step(model, ctx, lr=args.lr)
-    from repro.train.steps import build_init_fn
-
     init_fn, _ = build_init_fn(model, ctx)
     state = init_fn(jax.random.key(0))
     step_jit = jax.jit(
@@ -60,12 +62,14 @@ def main() -> None:
 
     ckpt = CheckpointManager(args.checkpoint_dir) if args.checkpoint_dir else None
     data = SyntheticTokens(cfg, args.batch, args.seq)
+    losses = []
     t0 = time.time()
     for i, host_batch in enumerate(data.iter()):
         if i >= args.steps:
             break
         batch = make_batch_on_mesh(host_batch, cfg, ctx)
         state, metrics = step_jit(state, batch)
+        losses.append(metrics["loss"])
         if i % 10 == 0 or i == args.steps - 1:
             loss = float(metrics["loss"])
             print(f"step {i:>5} loss {loss:.4f} ({(time.time()-t0):.1f}s)", flush=True)
@@ -73,9 +77,10 @@ def main() -> None:
             ckpt.save({"params": state.params}, i + 1)
     if ckpt:
         ckpt.wait()
+    return state, [float(x) for x in jax.device_get(losses)]
 
 
-def run_scenario(model: Model, args) -> None:
+def run_scenario(model: Model, args) -> tuple[TrainState, list[float]]:
     """Malleable training: the declarative trace drives the live runtime."""
     from repro.elastic import ElasticTrainer
     from repro.malleability import get_scenario
@@ -98,6 +103,7 @@ def run_scenario(model: Model, args) -> None:
           f"loss {hist[0].loss:.4f} -> {hist[-1].loss:.4f} "
           f"({time.time()-t0:.1f}s, {len(trainer.runtime.history)} reconfigs)",
           flush=True)
+    return trainer.state, trainer.losses()
 
 
 if __name__ == "__main__":

@@ -89,6 +89,22 @@ class TestCheckpointManager:
         assert step == 20
         assert np.array_equal(np.asarray(tree["x"]), np.arange(4.0) * 2)
 
+    def test_async_write_error_raised_by_wait(self, tmp_path, monkeypatch):
+        from repro.checkpoint import store
+
+        def failing_save(tree, directory, step):
+            raise OSError("disk full")
+
+        monkeypatch.setattr(store, "save_tree", failing_save)
+        cm = CheckpointManager(str(tmp_path))
+        cm.save({"x": np.arange(4.0)}, 10)
+        with pytest.raises(OSError, match="disk full"):
+            cm.wait()
+        cm.wait()  # reported once, then cleared
+        cm.save({"x": np.arange(4.0)}, 20)
+        with pytest.raises(OSError, match="disk full"):
+            cm.save({"x": np.arange(4.0)}, 30)  # the next save reports it
+
     def test_retention_keeps_last_k(self, tmp_path):
         cm = CheckpointManager(str(tmp_path), keep=2, async_save=False)
         for s in (1, 2, 3, 4):

@@ -53,3 +53,20 @@ def test_elastic_trainer_event_loop():
     )
     assert proc.returncode == 0, (proc.stderr[-3000:], proc.stdout[-500:])
     assert "ELASTIC_TRAINER_OK" in proc.stdout
+
+
+def test_from_scenario_names_the_chips_it_needs(monkeypatch):
+    """On a host with fewer devices than the scenario's peak, the error
+    says how many chips to run it on."""
+    import jax
+
+    from repro.configs import smoke_config
+    from repro.elastic import ElasticTrainer
+    from repro.malleability import get_scenario
+    from repro.models import Model
+
+    one = jax.devices()[:1]
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: one)
+    with pytest.raises(ValueError, match="needs 4 chips"):
+        ElasticTrainer.from_scenario(Model(smoke_config("xlstm_125m")),
+                                     get_scenario("steady-cycle"))

@@ -1,5 +1,8 @@
 """Pallas kernel validation: interpret-mode vs pure-jnp oracles.
 
+Every call passes ``interpret=True``: the wrappers compile for the TPU
+unless told otherwise (``tests/test_tpu_compile.py`` compiles them).
+
 Shape/dtype sweeps + property-based gate/mask behavior.
 """
 import jax
@@ -36,7 +39,7 @@ def test_flash_attention_shapes(B, H, KV, Sq, Sk, D, bq, bk, dtype):
     k = rand(ks[1], (B, KV, Sk, D), dtype)
     v = rand(ks[2], (B, KV, Sk, D), dtype)
     causal = Sq == Sk
-    out = flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk)
+    out = flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk, interpret=True)
     ref = attention_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32), **TOL[dtype]
@@ -49,7 +52,7 @@ def test_flash_attention_window(window):
     q = rand(ks[0], (1, 2, 128, 32), jnp.float32)
     k = rand(ks[1], (1, 2, 128, 32), jnp.float32)
     v = rand(ks[2], (1, 2, 128, 32), jnp.float32)
-    out = flash_attention(q, k, v, causal=True, window=window, block_q=32, block_k=32)
+    out = flash_attention(q, k, v, causal=True, window=window, block_q=32, block_k=32, interpret=True)
     ref = attention_ref(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
@@ -59,7 +62,7 @@ def test_flash_attention_softcap():
     q = rand(ks[0], (1, 2, 64, 32), jnp.float32) * 4
     k = rand(ks[1], (1, 2, 64, 32), jnp.float32) * 4
     v = rand(ks[2], (1, 2, 64, 32), jnp.float32)
-    out = flash_attention(q, k, v, causal=True, softcap=20.0, block_q=32, block_k=32)
+    out = flash_attention(q, k, v, causal=True, softcap=20.0, block_q=32, block_k=32, interpret=True)
     ref = attention_ref(q, k, v, causal=True, softcap=20.0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=3e-5, atol=3e-5)
 
@@ -79,7 +82,7 @@ def test_flash_attention_property(seed, logsq, group):
     q = rand(ks[0], (1, KV * group, S, D), jnp.float32)
     k = rand(ks[1], (1, KV, S, D), jnp.float32)
     v = rand(ks[2], (1, KV, S, D), jnp.float32)
-    out = flash_attention(q, k, v, causal=True, block_q=32, block_k=32)
+    out = flash_attention(q, k, v, causal=True, block_q=32, block_k=32, interpret=True)
     ref = attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
     assert float(jnp.max(jnp.abs(out))) <= float(jnp.max(jnp.abs(v))) + 1e-4
@@ -103,7 +106,7 @@ def test_ssd_shapes(B, S, H, P, N, chunk, dtype):
     A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.5)
     Bm = rand(ks[3], (B, S, N), dtype)
     Cm = rand(ks[4], (B, S, N), dtype)
-    y = ssd_scan(x, dt, A, Bm, Cm, chunk=chunk)
+    y = ssd_scan(x, dt, A, Bm, Cm, chunk=chunk, interpret=True)
     yr, _ = ssd_ref(x, dt, A, Bm, Cm)
     np.testing.assert_allclose(
         np.asarray(y, np.float32), np.asarray(yr, np.float32),
@@ -126,7 +129,7 @@ def test_ssd_chunked_matches_model_oracle():
     Cm = rand(ks[4], (B, S, N), jnp.float32)
     y_seq, st_seq = ssd_ref(x, dt, A, Bm, Cm)
     y_chk, st_chk = ssd_chunked(x, dt, A, Bm, Cm, chunk=16)
-    y_ker = ssd_scan(x, dt, A, Bm, Cm, chunk=16)
+    y_ker = ssd_scan(x, dt, A, Bm, Cm, chunk=16, interpret=True)
     np.testing.assert_allclose(np.asarray(y_chk), np.asarray(y_seq), rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(y_ker), np.asarray(y_seq), rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(st_chk), np.asarray(st_seq), rtol=1e-4, atol=1e-4)
@@ -143,7 +146,7 @@ def test_ssd_decay_property(seed):
     A = jnp.full((H,), -50.0)   # state dies between steps
     Bm = rand(ks[3], (B, S, N), jnp.float32)
     Cm = rand(ks[4], (B, S, N), jnp.float32)
-    y = ssd_scan(x, dt, A, Bm, Cm, chunk=8)
+    y = ssd_scan(x, dt, A, Bm, Cm, chunk=8, interpret=True)
     local = jnp.einsum("bsn,bsn->bs", Cm, Bm)[:, :, None, None] * 0.5 * x
     np.testing.assert_allclose(np.asarray(y), np.asarray(local), rtol=1e-3, atol=1e-3)
 
@@ -160,7 +163,7 @@ def test_mlstm_shapes(B, S, H, D, chunk):
     v = rand(ks[2], (B, S, H, D), jnp.float32)
     ig = jax.random.normal(ks[3], (B, S, H))
     fg = jax.random.normal(ks[4], (B, S, H)) + 1.0
-    h = mlstm_scan(q, k, v, ig, fg, chunk=chunk)
+    h = mlstm_scan(q, k, v, ig, fg, chunk=chunk, interpret=True)
     hr = mlstm_ref(q, k, v, ig, fg)
     np.testing.assert_allclose(np.asarray(h), np.asarray(hr), rtol=2e-4, atol=2e-4)
 
@@ -176,7 +179,7 @@ def test_mlstm_matches_model_chunked():
     ig = jax.random.normal(ks[3], (B, S, H))
     fg = jax.random.normal(ks[4], (B, S, H)) + 1.0
     h_model, _ = mlstm_chunked(q, k, v, ig, fg, chunk=16)
-    h_kernel = mlstm_scan(q, k, v, ig, fg, chunk=16)
+    h_kernel = mlstm_scan(q, k, v, ig, fg, chunk=16, interpret=True)
     h_seq = mlstm_ref(q, k, v, ig, fg)
     np.testing.assert_allclose(np.asarray(h_model), np.asarray(h_seq), rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(np.asarray(h_kernel), np.asarray(h_seq), rtol=2e-4, atol=2e-4)
@@ -194,7 +197,7 @@ def test_mlstm_extreme_gates_stable(seed):
     v = rand(ks[2], (B, S, H, D), jnp.float32)
     ig = jax.random.normal(ks[3], (B, S, H)) * 20    # exp gate up to e^20
     fg = jax.random.normal(ks[4], (B, S, H)) * 20
-    h = mlstm_scan(q, k, v, ig, fg, chunk=8)
+    h = mlstm_scan(q, k, v, ig, fg, chunk=8, interpret=True)
     assert bool(jnp.all(jnp.isfinite(h)))
     hr = mlstm_ref(q, k, v, ig, fg)
     np.testing.assert_allclose(np.asarray(h), np.asarray(hr), rtol=5e-4, atol=5e-4)

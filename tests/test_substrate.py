@@ -1,6 +1,7 @@
 """Substrate tests: checkpoint store, optimizer, data pipeline, sharding
 rule resolution."""
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -115,6 +116,30 @@ class TestData:
         it = d.iter(start_step=0)
         first = next(it)
         np.testing.assert_array_equal(first["tokens"], d.sample(0)["tokens"])
+
+    def test_prefetch_worker_error_reaches_consumer(self):
+        d = SyntheticTokens(self._cfg(), batch=2, seq=8, seed=0)
+
+        def sample(step):
+            if step == 1:
+                raise ValueError("bad sample")
+            return SyntheticTokens.sample(d, step)
+
+        d.sample = sample
+        got = []
+
+        def consume():
+            try:
+                for batch in d.iter(start_step=0):
+                    got.append(batch)
+            except ValueError as e:
+                got.append(e)
+
+        t = threading.Thread(target=consume, daemon=True)
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive(), "consumer still blocked on the queue"
+        assert len(got) == 2 and isinstance(got[1], ValueError)
 
 
 # --------------------------------------------------------------- sharding --
