@@ -7,6 +7,11 @@ TrainState onto the rebuilt mesh (stage 3), re-jits, and continues.
 Mesh-independent checkpoints — periodic, or CHECKPOINT-event-driven —
 cover the full-stop path: a RESTART event rebuilds the world at the
 target size and reads the params back from the latest snapshot.
+
+With :mod:`repro.obs` enabled, each step is a ``run_step`` span holding
+``drain``, ``mesh``, ``reshard`` (``.put``, ``.account``, ``.wait``),
+``rejit``, ``input``, ``dispatch`` and ``loss_sync``; JAX's compile
+events count into whichever is open.
 """
 from __future__ import annotations
 
@@ -15,6 +20,7 @@ from typing import Optional
 
 import jax
 
+from repro import obs
 from repro.checkpoint import CheckpointManager
 from repro.data import SyntheticTokens, make_batch_on_mesh
 from repro.malleability.scenarios import RuntimeAdapter, dispatch_event
@@ -30,6 +36,10 @@ from repro.train.steps import (
 from .reshard import transfer_stats
 from .rms import Event, EventKind, SimulatedRMS
 from .runtime import ElasticRuntime
+
+# The ``resize`` attribute of a step's ``run_step`` span, by the kind of
+# the event applied before it.
+_RESIZE = {EventKind.GROW: "expand", EventKind.SHRINK: "shrink"}
 
 
 @dataclass
@@ -112,13 +122,14 @@ class ElasticTrainer:
         return ShardingContext(mesh=self.runtime.mesh(("data",)), mode="train")
 
     def _rejit(self):
-        step_fn, shardings, _ = build_train_step(self.model, self._ctx, lr=self.lr)
-        self._step_fn = jax.jit(
-            step_fn,
-            in_shardings=(shardings, None),
-            out_shardings=(shardings, None),
-            donate_argnums=(0,),
-        )
+        with obs.span("rejit"):
+            step_fn, shardings, _ = build_train_step(self.model, self._ctx, lr=self.lr)
+            self._step_fn = jax.jit(
+                step_fn,
+                in_shardings=(shardings, None),
+                out_shardings=(shardings, None),
+                donate_argnums=(0,),
+            )
         return shardings
 
     def _init_state(self):
@@ -130,23 +141,39 @@ class ElasticTrainer:
     def _reshard_state(self, step: int = -1, charged_bytes: int = 0):
         """Stage 3: move the live TrainState onto the rebuilt mesh.
 
-        Logs the *measured* transfer stats of the parameter pytree next
-        to the engine-*charged* bytes for the drained events, so the two
-        accountings can be compared (they are equal when the engine uses
-        a :class:`~repro.elastic.reshard.PytreeBytesModel` and one event
+        Logs the *measured* transfer stats next to the engine-*charged*
+        bytes for the drained events: the parameter pytree's under
+        ``bytes_*``, so the two accountings can be compared (they are
+        equal when the engine uses a
+        :class:`~repro.elastic.reshard.PytreeBytesModel` and one event
         was drained; multi-event drains reshard once over the net mesh
-        change while the engine charges each hop).
+        change while the engine charges each hop), and the whole
+        TrainState's, Adam's moments included, under ``state_bytes_*``.
         """
-        _, shardings = train_state_shardings(self.model, self._ctx)
-        old_params = self._state.params
-        self._state = jax.tree.map(
-            lambda x, s: jax.device_put(x, s), self._state, shardings,
-        )
-        stats = dict(transfer_stats(old_params, self._state.params))
-        stats["step"] = step
-        stats["charged_bytes_moved"] = charged_bytes
-        self.transfer_log.append(stats)
+        with obs.span("reshard"):
+            _, shardings = train_state_shardings(self.model, self._ctx)
+            old = self._state
+            with obs.span("reshard.put"):
+                self._state = jax.tree.map(
+                    lambda x, s: jax.device_put(x, s), self._state, shardings,
+                )
+            with obs.span("reshard.account"):
+                whole = self._log_transfer(old, step, charged_bytes)
+            del old   # and any host copy a gather through the host left on it
+            obs.count("reshard.bytes_moved", whole["bytes_moved"])
+            obs.count("reshard.bytes_total", whole["bytes_total"])
+            if obs.enabled():
+                with obs.span("reshard.wait"):
+                    jax.block_until_ready(self._state)
         self._rejit()
+
+    def _log_transfer(self, old: TrainState, step: int, charged_bytes: int, **extra):
+        stats = dict(transfer_stats(old.params, self._state.params))
+        whole = transfer_stats(old, self._state)
+        stats.update({f"state_{k}": v for k, v in whole.items()})
+        stats.update(step=step, charged_bytes_moved=charged_bytes, **extra)
+        self.transfer_log.append(stats)
+        return whole
 
     def _restore_from_store(self, step: int, charged_bytes: int = 0):
         """SS-restart stage 3: params come back from the latest snapshot.
@@ -170,16 +197,12 @@ class ElasticTrainer:
         if tree is None:
             self._reshard_state(step=step, charged_bytes=charged_bytes)
             return
-        old_params = self._state.params
+        old = self._state
         state = jax.tree.map(
             lambda x, s: jax.device_put(x, s), self._state, shardings,
         )
         self._state = state._replace(params=tree["params"])
-        stats = dict(transfer_stats(old_params, self._state.params))
-        stats["step"] = step
-        stats["charged_bytes_moved"] = charged_bytes
-        stats["restored_from_step"] = ck_step
-        self.transfer_log.append(stats)
+        self._log_transfer(old, step, charged_bytes, restored_from_step=ck_step)
         self._rejit()
 
     # -------------------------------------------------------------------- events --
@@ -208,36 +231,53 @@ class ElasticTrainer:
     def run(self, steps: int) -> list[StepRecord]:
         if self._state is None:
             self._init_state()
-        for i in range(steps):
+        for _ in range(steps):
             step_no = len(self.history)
-            reconfigured = False
-            records_before = len(self.runtime.history)
-            for ev in self.rms.events_until(step_no):
-                reconfigured |= self._handle(ev)
-            if reconfigured:
-                self._ctx = self._make_ctx()
-                charged = sum(
-                    r.bytes_moved
-                    for r in self.runtime.history[records_before:]
-                )
-                if self._restore_pending:
-                    self._restore_pending = False
-                    self._restore_from_store(step_no, charged_bytes=charged)
-                else:
-                    self._reshard_state(step=step_no, charged_bytes=charged)
-            batch = make_batch_on_mesh(
-                self._data.sample(step_no), self.model.cfg, self._ctx
-            )
-            self._state, metrics = self._step_fn(self._state, batch)
-            self.history.append(
-                StepRecord(step=step_no, loss=float(metrics["loss"]),
-                           n_nodes=self.runtime.n_nodes)
-            )
-            if self._ckpt and (step_no + 1) % self.checkpoint_every == 0:
-                self._ckpt.save({"params": self._state.params}, step_no + 1)
+            with obs.span("run_step", step=step_no):
+                self._run_step(step_no)
         if self._ckpt:
             self._ckpt.wait()
         return self.history
+
+    def _run_step(self, step_no: int):
+        """Drain the events due, reconfigure if any applied, then train
+        one step."""
+        records_before = len(self.runtime.history)
+        applied, resize = 0, None
+        with obs.span("drain"):
+            for ev in self.rms.events_until(step_no):
+                if self._handle(ev):
+                    applied += 1
+                    resize = _RESIZE.get(ev.kind, resize)
+            obs.annotate(events=applied)
+        if applied:
+            with obs.span("mesh"):
+                self._ctx = self._make_ctx()
+            charged = sum(
+                r.bytes_moved
+                for r in self.runtime.history[records_before:]
+            )
+            if self._restore_pending:
+                self._restore_pending = False
+                self._restore_from_store(step_no, charged_bytes=charged)
+            else:
+                self._reshard_state(step=step_no, charged_bytes=charged)
+        obs.annotate(nodes=self.runtime.n_nodes)
+        if resize:
+            obs.annotate(resize=resize)
+        with obs.span("input"):
+            batch = make_batch_on_mesh(
+                self._data.sample(step_no), self.model.cfg, self._ctx
+            )
+        with obs.span("dispatch"):
+            self._state, metrics = self._step_fn(self._state, batch)
+        with obs.span("loss_sync"):
+            loss = float(metrics["loss"])
+        self.history.append(
+            StepRecord(step=step_no, loss=loss, n_nodes=self.runtime.n_nodes)
+        )
+        if self._ckpt and (step_no + 1) % self.checkpoint_every == 0:
+            self._ckpt.save({"params": self._state.params}, step_no + 1)
 
     # ------------------------------------------------------------------ queries --
     @property
