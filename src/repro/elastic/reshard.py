@@ -14,9 +14,22 @@ materializing any array (from ``Sharding.devices_indices_map``), so the
 cost simulator can charge the exact bytes the live reshard will move;
 :class:`PytreeBytesModel` packages that as a
 ``ReconfigEngine.bytes_model``.
+
+:func:`device_put_tree` does the move itself, on the devices.  JAX's
+``device_put`` of an array spread over several devices looks, for each
+target shard, for a source buffer that holds that shard whole; when one
+is missing (a shrink of an FSDP-sharded leaf onto fewer devices) it falls
+back to ``x._value``, copying the whole leaf to host memory and uploading
+it again, and leaves that host copy cached on the old array.  So such
+leaves are first gathered on their own mesh by a jitted identity whose
+output is replicated: an all-gather over the devices' links (a
+``device_put`` to the replicated sharding would take the same host path).
+The copy on a device the target uses is then handed to ``device_put``,
+which slices it there and copies the slices device to device.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Any, Optional, Sequence
 
@@ -24,21 +37,109 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
+
 
 def reshard_tree(tree: Any, mesh: Mesh, spec_tree: Any) -> Any:
     """Reshard every leaf of ``tree`` onto ``mesh`` with the given specs.
 
     ``spec_tree`` is either a single PartitionSpec applied to all leaves or
-    a pytree of specs matching ``tree``'s structure.  Uses ``device_put``,
-    which moves only the shards that change placement.
+    a pytree of specs matching ``tree``'s structure.  Uses
+    :func:`device_put_tree`, which moves only the shards that change
+    placement, and only between devices.
     """
     if isinstance(spec_tree, P) or spec_tree is None:
         specs = jax.tree.map(lambda _: spec_tree or P(), tree)
     else:
         specs = spec_tree
-    return jax.tree.map(
-        lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), tree, specs
+    return device_put_tree(
+        tree, jax.tree.map(lambda _, s: NamedSharding(mesh, s), tree, specs)
     )
+
+
+def _identity(x: jax.Array) -> jax.Array:
+    return x
+
+
+@functools.lru_cache(maxsize=16)
+def _replicate_on(mesh: Mesh):
+    """One jitted identity per mesh, its outputs whole on every device."""
+    return jax.jit(_identity, out_shardings=NamedSharding(mesh, P()))
+
+
+def _held_indices(sharding: Any, shape: tuple[int, ...]) -> set[tuple]:
+    # The (start, stop) bounds JAX matches shards by (``hashed_index``).
+    return {
+        tuple((i.start, i.stop) if isinstance(i, slice) else i for i in idx)
+        for idx in sharding.devices_indices_map(shape).values()
+    }
+
+
+@functools.lru_cache(maxsize=4096)
+def _needs_gather(old: Any, new: Any, shape: tuple[int, ...]) -> bool:
+    """Would ``device_put`` from ``old`` onto ``new`` go through the host?
+
+    Yes when the source spans several devices of a mesh and some target
+    shard is held whole by none of its buffers.  A single-device source
+    is sliced on its device; a source that is no ``NamedSharding`` has no
+    mesh to gather on and takes ``device_put`` as it is.
+    """
+    if old.num_devices == 1 or not isinstance(old, NamedSharding):
+        return False
+    return not _held_indices(new, shape) <= _held_indices(old, shape)
+
+
+def _gather_onto(x: jax.Array, target: Any) -> jax.Array:
+    """Gather ``x`` whole onto every device of its mesh, wait for it,
+    then place the copy on a device ``target`` uses (else its first)
+    onto ``target``.  The other copies are dropped on return."""
+    whole = _replicate_on(x.sharding.mesh)(x).block_until_ready()
+    shards = whole.addressable_shards
+    copy = next((s.data for s in shards if s.device in target.device_set),
+                shards[0].data)
+    return jax.device_put(copy, target)
+
+
+def device_put_tree(tree: Any, shardings: Any) -> Any:
+    """``jax.device_put`` of every leaf of ``tree`` onto the matching leaf
+    of ``shardings``, without a copy through host memory.
+
+    A leaf that some old buffer can already supply shard by shard (an
+    expansion from one device, a replicated leaf, an unchanged layout)
+    goes to ``device_put`` as it is.  Each of the others is gathered on
+    its mesh first (the module doc says why) and lands from its gathered
+    copy.  One program per leaf, each waited for before the next: in one
+    program the leaves' gathers all overlap and each needs a temporary
+    as large as its output (the TPU compiler lays out an all-gather on a
+    minor dimension apart from the result), which for a 6.9 GB state on
+    four v5e chips would fill each chip; with many in flight, the
+    devices the target leaves out hold every gathered copy at once, and
+    XLA's CPU backend has aborted on a collective's 40 s rendezvous
+    timeout (8 host devices under load).
+
+    With :mod:`repro.obs` enabled the gathers are one ``reshard.gather``
+    span, and the bytes of the gathered leaves count as
+    ``reshard.gathered_bytes`` (0 when none) in the span open around the
+    call.
+
+    Args:
+        tree: pytree of ``jax.Array``.
+        shardings: pytree of ``Sharding`` with ``tree``'s structure.
+    Returns:
+        The pytree placed by ``shardings``, bit for bit equal to ``tree``.
+    """
+    leaves, treedef = jax.tree.flatten(tree)
+    targets = treedef.flatten_up_to(shardings)
+    gather = [i for i, (x, s) in enumerate(zip(leaves, targets))
+              if isinstance(x, jax.Array) and _needs_gather(x.sharding, s, x.shape)]
+    out = [x if i in gather else jax.device_put(x, s)
+           for i, (x, s) in enumerate(zip(leaves, targets))]
+    if gather:
+        with obs.span("reshard.gather"):
+            for i in gather:
+                out[i] = _gather_onto(leaves[i], targets[i])
+    obs.count("reshard.gathered_bytes", sum(leaves[i].nbytes for i in gather))
+    return treedef.unflatten(out)
 
 
 def _index_key(index: tuple, shape: tuple[int, ...]) -> tuple:

@@ -9,7 +9,8 @@ cover the full-stop path: a RESTART event rebuilds the world at the
 target size and reads the params back from the latest snapshot.
 
 With :mod:`repro.obs` enabled, each step is a ``run_step`` span holding
-``drain``, ``mesh``, ``reshard`` (``.put``, ``.account``, ``.wait``),
+``drain``, ``mesh``, ``reshard`` (``.put``, with ``.gather`` inside it
+when leaves are gathered on the old mesh, ``.account``, ``.wait``),
 ``rejit``, ``input``, ``dispatch`` and ``loss_sync``; JAX's compile
 events count into whichever is open.
 """
@@ -33,7 +34,7 @@ from repro.train.steps import (
     train_state_shardings,
 )
 
-from .reshard import transfer_stats
+from .reshard import device_put_tree, transfer_stats
 from .rms import Event, EventKind, SimulatedRMS
 from .runtime import ElasticRuntime
 
@@ -154,12 +155,10 @@ class ElasticTrainer:
             _, shardings = train_state_shardings(self.model, self._ctx)
             old = self._state
             with obs.span("reshard.put"):
-                self._state = jax.tree.map(
-                    lambda x, s: jax.device_put(x, s), self._state, shardings,
-                )
+                self._state = device_put_tree(self._state, shardings)
             with obs.span("reshard.account"):
                 whole = self._log_transfer(old, step, charged_bytes)
-            del old   # and any host copy a gather through the host left on it
+            del old
             obs.count("reshard.bytes_moved", whole["bytes_moved"])
             obs.count("reshard.bytes_total", whole["bytes_total"])
             if obs.enabled():
@@ -198,10 +197,9 @@ class ElasticTrainer:
             self._reshard_state(step=step, charged_bytes=charged_bytes)
             return
         old = self._state
-        state = jax.tree.map(
-            lambda x, s: jax.device_put(x, s), self._state, shardings,
+        self._state = device_put_tree(
+            old._replace(params=tree["params"]), shardings,
         )
-        self._state = state._replace(params=tree["params"])
         self._log_transfer(old, step, charged_bytes, restored_from_step=ck_step)
         self._rejit()
 

@@ -161,38 +161,61 @@ SCRIPT = textwrap.dedent("""
     import json, os, sys
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax
+    import numpy as np
+    from jax.sharding import Mesh
+    import repro.elastic.trainer as trainer_mod
     from repro import obs
     from repro.configs import smoke_config
     from repro.elastic import DevicePool, ElasticRuntime, ElasticTrainer, SimulatedRMS
     from repro.elastic.rms import Event, EventKind
     from repro.models import Model
+    from repro.parallel.sharding import ShardingContext
+    from repro.train.steps import train_state_shardings
 
-    rms = SimulatedRMS(script=[
-        Event(step=2, kind=EventKind.GROW, target_nodes=4),
-        Event(step=4, kind=EventKind.SHRINK, nodes=(1, 2, 3)),
-        Event(step=5, kind=EventKind.CHECKPOINT),
-        Event(step=6, kind=EventKind.RESTART, target_nodes=2),
-    ])
-    tr = ElasticTrainer(model=Model(smoke_config("stablelm_3b")),
-                        runtime=ElasticRuntime(pool=DevicePool(), initial_nodes=1),
-                        rms=rms, batch=4, seq=16, checkpoint_dir=sys.argv[1],
-                        checkpoint_every=1000)
+    def trainer(ckpt_dir):
+        rms = SimulatedRMS(script=[
+            Event(step=2, kind=EventKind.GROW, target_nodes=4),
+            Event(step=4, kind=EventKind.SHRINK, nodes=(1, 2, 3)),
+            Event(step=5, kind=EventKind.CHECKPOINT),
+            Event(step=6, kind=EventKind.RESTART, target_nodes=2),
+        ])
+        return ElasticTrainer(model=Model(smoke_config("stablelm_3b")),
+                              runtime=ElasticRuntime(pool=DevicePool(), initial_nodes=1),
+                              rms=rms, batch=4, seq=16, checkpoint_dir=ckpt_dir,
+                              checkpoint_every=1000)
+
+    tr = trainer(sys.argv[1])
     tr.run(2)
     off = obs.records()
     obs.enable()
     tr.run(5)
     obs.disable()
     whole = sum(x.nbytes for x in jax.tree.leaves(tr.state))
+    _, on4 = train_state_shardings(tr.model, ShardingContext(
+        mesh=Mesh(np.asarray(jax.devices()[:4]), ("data",)), mode="train"))
+    sharded = sum(x.nbytes for x, s in zip(jax.tree.leaves(tr.state),
+                                           jax.tree.leaves(on4))
+                  if not s.is_fully_replicated)
+
+    # The same run with every leaf moved by plain device_put, as before
+    # the gather.
+    trainer_mod.device_put_tree = lambda tree, shardings: jax.tree.map(
+        jax.device_put, tree, shardings)
+    plain = trainer(sys.argv[2])
+    plain.run(7)
     print(json.dumps({"off": off, "records": obs.records(), "whole": whole,
-                      "log": tr.transfer_log,
-                      "nodes": [r.n_nodes for r in tr.history]}))
+                      "sharded": sharded, "log": tr.transfer_log,
+                      "nodes": [r.n_nodes for r in tr.history],
+                      "losses": tr.losses(), "plain_log": plain.transfer_log,
+                      "plain_losses": plain.losses()}))
 """)
 
 
 @pytest.fixture(scope="module")
 def traced_resizes(tmp_path_factory):
     env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
-    p = subprocess.run([sys.executable, "-c", SCRIPT, str(tmp_path_factory.mktemp("ckpt"))],
+    p = subprocess.run([sys.executable, "-c", SCRIPT, str(tmp_path_factory.mktemp("ckpt")),
+                        str(tmp_path_factory.mktemp("ckpt_plain"))],
                        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
     assert p.returncode == 0, p.stderr[-3000:]
     return json.loads(p.stdout.strip().splitlines()[-1])
@@ -252,3 +275,34 @@ def test_transfer_log_counts_the_whole_train_state(traced_resizes):
     assert expand["state_bytes_total"] >= r["whole"]
     assert restore["restored_from_step"] == 5
     assert "state_bytes_moved" in restore
+
+
+def test_shrink_gathers_the_sharded_state_on_the_chips(traced_resizes):
+    """The shrink onto one chip gathers every leaf the four chips shard,
+    in one reshard.gather span under reshard.put; the expansion from one
+    chip and the restart's move from one chip to two gather nothing."""
+    r = traced_resizes
+    recs = r["records"]
+    steps = {s["attrs"]["step"]: s for s in recs if s["name"] == "run_step"}
+    assert r["sharded"] > 0
+    gathered = {}
+    for step in (2, 4, 6):
+        sub = _subtree(recs, steps[step])
+        gathered[step] = sum(x["counters"].get("reshard.gathered_bytes", 0) for x in sub)
+        spans = [x for x in sub if x["name"] == "reshard.gather"]
+        if step == 4:
+            put = next(x for x in sub if x["name"] == "reshard.put")
+            assert [x["parent"] for x in spans] == [put["id"]]
+            assert put["counters"]["reshard.gathered_bytes"] == r["sharded"]
+        else:
+            assert spans == []
+    assert gathered == {2: 0, 4: r["sharded"], 6: 0}
+
+
+def test_gather_leaves_transfer_log_and_losses_as_plain_device_put(traced_resizes):
+    """The gather is a bit-exact copy onto the same shardings: the
+    transfer log (params and whole state) and every loss equal those of
+    the same run with plain device_put."""
+    r = traced_resizes
+    assert r["log"] == r["plain_log"]
+    assert r["losses"] == r["plain_losses"]

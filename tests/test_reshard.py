@@ -4,6 +4,7 @@ predicted (devices_indices_map) twin that the cost simulator charges.
 Multi-device cases run in a subprocess (the main test process must keep
 seeing 1 device); the empty-tree edge cases run in-process.
 """
+import json
 import os
 import subprocess
 import sys
@@ -113,3 +114,165 @@ def test_predicted_equals_measured_across_reshards():
     )
     assert proc.returncode == 0, (proc.stderr[-3000:], proc.stdout[-500:])
     assert "ALL_RESHARD_CASES_OK" in proc.stdout
+
+
+PUT_TREE_SCRIPT = textwrap.dedent("""
+    import json, os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax._src import array as jax_array
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro import obs
+    from repro.elastic.reshard import device_put_tree
+
+    devs = jax.devices()
+
+    def mesh(ids):
+        return Mesh(np.asarray([devs[i] for i in ids], dtype=object), ("data",))
+
+    def shardings(m):
+        return {"fsdp": NamedSharding(m, P("data")),
+                "cols": NamedSharding(m, P(None, "data")),
+                "replicated": NamedSharding(m, P()),
+                "step": NamedSharding(m, P())}
+
+    tree = {"fsdp": jnp.arange(16 * 8, dtype=jnp.float32).reshape(16, 8) * 1.5,
+            "cols": jnp.arange(4 * 8, dtype=jnp.float32).astype(jnp.bfloat16).reshape(4, 8),
+            "replicated": jnp.linspace(-1, 1, 24, dtype=jnp.float32).reshape(4, 6),
+            "step": jnp.asarray(7, jnp.int32)}
+    sharded_bytes = tree["fsdp"].nbytes + tree["cols"].nbytes
+
+    def host_read(self):
+        raise AssertionError(f"leaf {self.shape} read through host memory")
+
+    real_value = jax_array.ArrayImpl._value
+    cases = {"4to1": ([0, 1, 2, 3], [0]), "4to2": ([0, 1, 2, 3], [0, 1]),
+             "2to4": ([0, 1], [0, 1, 2, 3]), "1to4": ([0], [0, 1, 2, 3]),
+             "4to4_elsewhere": ([0, 1, 2, 3], [4, 5, 6, 7])}
+    out = {}
+    for name, (src, dst) in cases.items():
+        old = jax.device_put(tree, shardings(mesh(src)))
+        jax.block_until_ready(old)
+        target = shardings(mesh(dst))
+        runs = []
+        for _ in range(2):
+            obs.reset()
+            obs.enable()
+            jax_array.ArrayImpl._value = property(host_read)
+            try:
+                with obs.span("put"):
+                    new = device_put_tree(old, target)
+                    jax.block_until_ready(new)
+            finally:
+                jax_array.ArrayImpl._value = real_value
+                obs.disable()
+            runs.append(obs.records())
+        host_copies = [k for k, x in old.items() if x._npy_value is not None]
+        plain = jax.device_put(old, target)
+        same = {k: (new[k].sharding == plain[k].sharding
+                    and new[k].sharding == target[k]
+                    and np.asarray(new[k]).tobytes() == np.asarray(plain[k]).tobytes()
+                    and np.asarray(new[k]).tobytes() == np.asarray(tree[k]).tobytes())
+                for k in tree}
+        first, second = runs
+        out[name] = {
+            "same": same, "host_copies": host_copies,
+            "gathered": [r[0]["counters"]["reshard.gathered_bytes"] for r in runs],
+            "gather_spans": [[x["name"] for x in r[1:]] for r in runs],
+            "gather_compiles": [sum(x["counters"].get("compile.count", 0)
+                                    for x in r if x["name"] == "reshard.gather")
+                                for r in runs],
+        }
+    print(json.dumps({"cases": out, "sharded_bytes": sharded_bytes}))
+""")
+
+
+@pytest.fixture(scope="module")
+def put_tree_cases():
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, "-c", PUT_TREE_SCRIPT], capture_output=True, text=True,
+        timeout=600, env=env,
+    )
+    assert proc.returncode == 0, (proc.stderr[-3000:], proc.stdout[-500:])
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+GATHERS = {"4to1": True, "4to2": True, "2to4": True, "1to4": False,
+           "4to4_elsewhere": False}
+
+
+@pytest.mark.parametrize("case", sorted(GATHERS))
+def test_device_put_tree_matches_device_put_without_host_copy(put_tree_cases, case):
+    """device_put_tree gives plain device_put's values, bit for bit, and
+    its shardings, with ArrayImpl._value patched to raise: no leaf,
+    sharded, replicated or scalar, goes through host memory, and none of
+    the old arrays is left holding a host copy."""
+    got = put_tree_cases["cases"][case]
+    assert got["same"] == {"fsdp": True, "cols": True, "replicated": True,
+                           "step": True}
+    assert got["host_copies"] == []
+
+
+@pytest.mark.parametrize("case", sorted(GATHERS))
+def test_device_put_tree_gathers_only_what_no_buffer_holds(put_tree_cases, case):
+    """The two sharded leaves are gathered when some target shard is held
+    whole by no old buffer (4->1, 4->2, 2->4), in one reshard.gather span
+    whose programs compile on the first call only.  A move from one
+    device, or onto the same layout on other devices, gathers nothing."""
+    got = put_tree_cases["cases"][case]
+    if GATHERS[case]:
+        assert got["gathered"] == [put_tree_cases["sharded_bytes"]] * 2
+        assert got["gather_spans"] == [["reshard.gather"]] * 2
+        first, second = got["gather_compiles"]
+        assert first >= 1 and second == 0
+    else:
+        assert got["gathered"] == [0, 0]
+        assert got["gather_spans"] == [[], []]
+
+
+CONCURRENT_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.elastic.reshard import device_put_tree
+
+    devs = jax.devices()
+
+    def mesh(k):
+        return Mesh(np.asarray(devs[:k]), ("data",))
+
+    tree = {f"l{i}": jnp.arange(256 * 512, dtype=jnp.float32).reshape(256, 512) + i
+            for i in range(60)}
+    for rep in range(20):
+        old = jax.device_put(tree, NamedSharding(mesh(8), P("data")))
+        target = NamedSharding(mesh(2 if rep % 2 else 4), P(None, "data"))
+        new = device_put_tree(old, {k: target for k in tree})
+        jax.block_until_ready(new)
+    assert all(np.array_equal(np.asarray(new[k]), np.asarray(tree[k])) for k in tree)
+    print("GATHERS_DONE")
+""")
+
+
+@pytest.mark.slow
+def test_many_gathers_complete_on_a_loaded_cpu():
+    """Sixty leaves gathered 8 -> 4 and 8 -> 2 devices, twenty times, in
+    four processes at once.  With every leaf's all-gather in flight
+    together, XLA's CPU backend aborted most such processes on a
+    collective's 40 s rendezvous timeout; one gather at a time, all
+    finish."""
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
+    procs = [subprocess.Popen([sys.executable, "-c", CONCURRENT_SCRIPT],
+                              stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+                              text=True, env=env) for _ in range(4)]
+    outs = [p.communicate(timeout=600) for p in procs]
+    for p, (out, err) in zip(procs, outs):
+        assert p.returncode == 0, err[-3000:]
+        assert "GATHERS_DONE" in out
