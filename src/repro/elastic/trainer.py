@@ -3,16 +3,26 @@
 Wraps a Model + ElasticRuntime + SimulatedRMS into one training loop:
 every step it drains due RMS events, reconfigures (expand via the
 parallel spawn plan, shrink/fail/straggler via TS), reshards the live
-TrainState onto the rebuilt mesh (stage 3), re-jits, and continues.
+TrainState onto the rebuilt mesh (stage 3), takes the jitted step for
+that mesh, and continues.
 Mesh-independent checkpoints — periodic, or CHECKPOINT-event-driven —
 cover the full-stop path: a RESTART event rebuilds the world at the
 target size and reads the params back from the latest snapshot.
 
+The trainer keeps one jitted train step per allocation, keyed on the
+mesh (its devices in mesh order, its shape and axis names) and the
+context's ``mode``.  A resize back to an allocation seen before takes
+the stored step, which JAX dispatches without tracing, lowering or a
+persistent-cache load; a mesh of the same size over other devices is
+another key and compiles anew.  Entries hold executables, not state,
+and live as long as the trainer.
+
 With :mod:`repro.obs` enabled, each step is a ``run_step`` span holding
 ``drain``, ``mesh``, ``reshard`` (``.put``, with ``.gather`` inside it
 when leaves are gathered on the old mesh, ``.account``, ``.wait``),
-``rejit``, ``input``, ``dispatch`` and ``loss_sync``; JAX's compile
-events count into whichever is open.
+``rejit`` (counter ``rejit.cache_hits`` or ``rejit.cache_misses``),
+``input``, ``dispatch`` and ``loss_sync``; JAX's compile events count
+into whichever is open.
 """
 from __future__ import annotations
 
@@ -67,6 +77,7 @@ class ElasticTrainer:
     def __post_init__(self):
         self._ctx = self._make_ctx()
         self._step_fn = None
+        self._steps: dict[tuple, tuple] = {}
         self._restore_pending = False
         self._state: Optional[TrainState] = None
         self._data = SyntheticTokens(self.model.cfg, self.batch, self.seq, self.seed)
@@ -123,14 +134,35 @@ class ElasticTrainer:
         return ShardingContext(mesh=self.runtime.mesh(("data",)), mode="train")
 
     def _rejit(self):
+        """Point ``_step_fn`` at the jitted train step for the current
+        mesh and return the ``TrainState`` shardings it takes.
+
+        Steps are kept by ``(mesh, mode)``; ``Mesh`` equality is its
+        devices in order, shape and axis names, which is what JAX's
+        executable cache compares.  A miss builds and stores the step
+        (``rejit.cache_misses``); a hit returns the stored one and its
+        shardings without building (``rejit.cache_hits``).  ``model`` and
+        ``lr`` are fixed for the trainer's life, so they are not in the
+        key.
+        """
+        key = (self._ctx.mesh, self._ctx.mode)
         with obs.span("rejit"):
-            step_fn, shardings, _ = build_train_step(self.model, self._ctx, lr=self.lr)
-            self._step_fn = jax.jit(
-                step_fn,
-                in_shardings=(shardings, None),
-                out_shardings=(shardings, None),
-                donate_argnums=(0,),
-            )
+            entry = self._steps.get(key)
+            if entry is None:
+                obs.count("rejit.cache_misses")
+                step_fn, shardings, _ = build_train_step(self.model, self._ctx, lr=self.lr)
+                entry = self._steps[key] = (
+                    jax.jit(
+                        step_fn,
+                        in_shardings=(shardings, None),
+                        out_shardings=(shardings, None),
+                        donate_argnums=(0,),
+                    ),
+                    shardings,
+                )
+            else:
+                obs.count("rejit.cache_hits")
+            self._step_fn, shardings = entry
         return shardings
 
     def _init_state(self):

@@ -28,6 +28,12 @@ listeners that count JAX's compile events into the innermost open span:
 - ``compile.cache_hits``, ``compile.cache_misses``: persistent-cache
   lookups.
 
+The program counts its own beside them: ``reshard.bytes_moved``,
+``reshard.bytes_total`` and ``reshard.gathered_bytes`` in a resize's
+reshard, and ``rejit.cache_hits`` or ``rejit.cache_misses`` in each
+``rejit`` span, as ``ElasticTrainer`` finds its jitted step for the
+mesh stored or builds it.
+
 ``disable()`` unregisters them.  ``records()`` and ``reset()`` are the
 only readout.  Spans nest on one stack: open and close them from the
 thread that runs the loop.
